@@ -89,6 +89,9 @@ def main():
         err = float(jnp.abs(out - ref).max())
         print(f"spmd_pipeline over {n_dev} devices "
               f"(collective_permute GPipe): max err vs sequential {err:.2e}")
+    else:
+        print(f"spmd_pipeline skipped: needs 4 devices, found {n_dev} "
+              f"(chip_smoke.py --four-chips runs it on four TPUs)")
 
 
 if __name__ == "__main__":

@@ -3,7 +3,9 @@ own evaluation models (BERT-Large, GPT-3 24L).
 
 Each ``<arch>.py`` module exports ``CONFIG`` (exact assigned spec, source
 cited) and ``SMOKE`` (reduced same-family variant: <=2 periods,
-d_model<=512, <=4 experts, runnable on CPU).
+d_model<=512, <=4 experts, runnable on CPU).  A module may also export
+``CHIP``: the published widths cut to one chip's share of a stated
+deployment, with the cuts listed in the module's ``reduced``.
 """
 from __future__ import annotations
 
@@ -57,6 +59,13 @@ def get_config(name: str) -> ModelConfig:
 def get_smoke_config(name: str) -> ModelConfig:
     mod = importlib.import_module(f"repro.configs.{_ARCH_MODULES[name]}")
     return mod.SMOKE
+
+
+def get_chip_config(name: str) -> ModelConfig:
+    mod = importlib.import_module(f"repro.configs.{_ARCH_MODULES[name]}")
+    if not hasattr(mod, "CHIP"):
+        raise ValueError(f"{name} has no chip-share preset (CHIP) yet")
+    return mod.CHIP
 
 
 def shape_applicable(cfg: ModelConfig, shape: InputShape) -> Tuple[bool, str]:

@@ -319,7 +319,6 @@ def spmd_pipeline(stage_fn: Callable, stacked_params, x_microbatches: Array,
     total = n_micro + n_stages - 1                     # fill + drain ticks
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def per_stage(params, xs):
         params = jax.tree.map(lambda a: a[0], params)  # my stage's params
@@ -354,9 +353,9 @@ def spmd_pipeline(stage_fn: Callable, stacked_params, x_microbatches: Array,
         return outs[None]
 
     spec_params = jax.tree.map(lambda _: P(axis), stacked_params)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(spec_params, P(axis)),
-                   out_specs=P(axis), check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(spec_params, P(axis)),
+                       out_specs=P(axis), check_vma=False)
     # replicate microbatches across stages (each stage uses only what it needs)
     xs_tiled = jnp.broadcast_to(x_microbatches[None],
                                 (n_stages,) + x_microbatches.shape)

@@ -3,10 +3,5 @@
 # use_kernel=True)) plus the seed flash-attention / SSM-scan / int8
 # kernels.  Public surface = the jit'd wrappers in ops.py; parity
 # oracles in ref.py / the model's blocked attention.  See README.md for
-# the grid/BlockSpec layouts and the interpret-mode CPU story.
-import jax.experimental.pallas.tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; one shim here so the
-# kernels import (and run in interpret mode) on either side of the rename
-CompilerParams = (getattr(_pltpu, "CompilerParams", None)
-                  or _pltpu.TPUCompilerParams)
+# the grid/BlockSpec layouts and how the backend picks compiled or
+# interpret mode.

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 NEG_INF = -1e30
 
 DEFAULT_BLOCK_Q = 128
@@ -78,7 +76,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         scale: float | None = None,
                         block_q: int = DEFAULT_BLOCK_Q,
                         block_k: int = DEFAULT_BLOCK_K,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """q: (B, Hq, S, D); k, v: (B, Hkv, T, D).  Returns (B, Hq, S, D).
 
     Pads S/T/D to block multiples; D padding is free for the softmax
@@ -132,7 +130,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dp), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)

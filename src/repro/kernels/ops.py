@@ -1,13 +1,13 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel
-body runs as traced JAX ops); on a real TPU set ``interpret=False`` (or
-env REPRO_PALLAS_COMPILE=1) to compile through Mosaic.  Model code calls
-these wrappers, never ``pallas_call`` directly.
+The backend decides how a kernel runs, and nothing else does: on a TPU
+every wrapper compiles through Mosaic, on the CPU backend it runs in
+interpret mode (the kernel body as traced JAX ops), and any other
+backend is an error.  Model code calls these wrappers, never
+``pallas_call`` directly.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -18,7 +18,17 @@ from repro.kernels import paged_attention as _pa
 from repro.kernels import quantize as _q
 from repro.kernels import ssm_scan as _s
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+
+def _interpret() -> bool:
+    """Interpret mode on the CPU backend, Mosaic on a TPU.  Read while the
+    calling wrapper traces, so importing this module touches no device."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"repro.kernels: no Pallas path for backend "
+                       f"{backend!r} (TPU compiles, CPU interprets)")
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "scale",
@@ -30,7 +40,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     """q: (B,Hq,S,D); k,v: (B,Hkv,T,D) -> (B,Hq,S,D)."""
     return _fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
                                    scale=scale, block_q=block_q,
-                                   block_k=block_k, interpret=_INTERPRET)
+                                   block_k=block_k, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("scale", "window", "softcap"))
@@ -46,17 +56,17 @@ def paged_attention(q, k, v, pos, table, q_pos, *,
     return _pa.paged_attention_fwd(q, k, v, pos, table, q_pos, scale=scale,
                                    window=window, softcap=softcap,
                                    q_extra=q_extra, k_extra=k_extra,
-                                   interpret=_INTERPRET)
+                                   interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("block",))
 def int8_quantize(x, *, block: int = _q.DEFAULT_BLOCK):
-    return _q.int8_quantize(x, block=block, interpret=_INTERPRET)
+    return _q.int8_quantize(x, block=block, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("shape", "dtype"))
 def int8_dequantize(q, scales, shape, dtype=jnp.float32):
-    return _q.int8_dequantize(q, scales, shape, dtype, interpret=_INTERPRET)
+    return _q.int8_dequantize(q, scales, shape, dtype, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("chunk", "di_block"))
@@ -64,10 +74,10 @@ def mamba_scan(x, dt, b, c, a, *, chunk: int = _s.DEFAULT_CHUNK,
                di_block: int = _s.DEFAULT_DI_BLOCK):
     """Selective scan: x,dt (B,S,di); b,c (B,S,ds); a (di,ds)."""
     return _s.mamba_scan(x, dt, b, c, a, chunk=chunk, di_block=di_block,
-                         interpret=_INTERPRET)
+                         interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("chunk",))
 def rwkv_scan(r, k, v, w, u, *, chunk: int = _s.DEFAULT_CHUNK):
     """RWKV6 wkv: r,k,v,w (B,S,H,hd); u (H,hd)."""
-    return _s.rwkv_scan(r, k, v, w, u, chunk=chunk, interpret=_INTERPRET)
+    return _s.rwkv_scan(r, k, v, w, u, chunk=chunk, interpret=_interpret())

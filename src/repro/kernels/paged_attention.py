@@ -37,11 +37,14 @@ paged_kernel_bench`` compares against the gather path's XLA-costed
 bytes.  (Interpret mode emulates block DMA with loop-carried copies, so
 its own XLA byte count measures the interpreter, not the kernel.)
 
-Compiled mode pads head dims to lane multiples (128) — exact, zero pad
-contributes nothing to dots or softmax — and wants ``page_size`` a
-sublane multiple (8 for f32 pools, 16 for bf16).  ``interpret=True``
-(the default on this CPU container, see ``repro.kernels.ops``) runs the
-same body as traced JAX ops, which is also what the production dry-run
+Mosaic tiles the last two dims of every block in (8, 128) units or
+takes them whole, so the kernel reads the pools through a free
+``(N, page, Hkv*D)`` view — one head's page is a ``(page, D)`` block —
+and wants ``page_size`` a sublane multiple (8 for f32 pools, 16 for
+bf16).  A head dim that is not a lane multiple is zero-padded (exact)
+only where several kv heads share the lane axis.  ``interpret=True``
+runs the same body as traced JAX ops: ``repro.kernels.ops`` selects it
+on the CPU backend only, which is also what the production dry-run
 lowers on the host-device mesh.
 """
 from __future__ import annotations
@@ -53,14 +56,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 NEG_INF = -1e30
 
 
 def _spec_plan(B: int, Hq: int, Hkv: int, page: int, n_cols: int,
                D: int, Dv: int, De: int, itemsize: int):
     """The kernel's block layout AND its DMA schedule from one source.
+
+    Block shapes are over the kernel's operand views (see
+    ``paged_attention_fwd``): q/out ``(B, Hkv, G, D)``, K/V/rope pools
+    ``(N, page, Hkv*D)`` and positions ``(N, 1, page)``, so every block's
+    last two dims are whole or ``(page, D)`` tiles Mosaic accepts.
 
     Returns (in_specs, out_spec, bytes_accessed) where each entry of the
     plan is one ``BlockSpec`` plus the number of distinct fetches the
@@ -78,26 +84,27 @@ def _spec_plan(B: int, Hq: int, Hkv: int, page: int, n_cols: int,
         return (bh // Hkv, bh % Hkv, 0, 0)
 
     def kv_index(bh, ic, tab, qp):
-        # the scalar-prefetched table drives the page DMA: one pool page
-        # per grid step, straight from HBM (unallocated -> block 0, the
-        # body force-masks it)
-        return (jnp.maximum(tab[bh // Hkv, ic], 0), 0, bh % Hkv, 0)
+        # the scalar-prefetched table drives the page DMA: one head of
+        # one pool page per grid step, straight from HBM (unallocated ->
+        # block 0, the body force-masks it); the last index picks the
+        # head's D-wide lane block of the flattened (Hkv*D) axis
+        return (jnp.maximum(tab[bh // Hkv, ic], 0), 0, bh % Hkv)
 
     def pos_index(bh, ic, tab, qp):
-        return (jnp.maximum(tab[bh // Hkv, ic], 0), 0)
+        return (jnp.maximum(tab[bh // Hkv, ic], 0), 0, 0)
 
     per_head = B * Hkv                      # fetched once per (slot, head)
     per_page = B * Hkv * n_cols             # re-fetched every page column
     plan = [  # (BlockSpec, fetches, itemsize)
         (pl.BlockSpec((1, 1, G, D), head_index), per_head, itemsize),
-        (pl.BlockSpec((1, page, 1, D), kv_index), per_page, itemsize),
-        (pl.BlockSpec((1, page, 1, Dv), kv_index), per_page, itemsize),
-        (pl.BlockSpec((1, page), pos_index), per_page, 4),
+        (pl.BlockSpec((1, page, D), kv_index), per_page, itemsize),
+        (pl.BlockSpec((1, page, Dv), kv_index), per_page, itemsize),
+        (pl.BlockSpec((1, 1, page), pos_index), per_page, 4),
     ]
     if De:
         plan += [(pl.BlockSpec((1, 1, G, De), head_index), per_head,
                   itemsize),
-                 (pl.BlockSpec((1, page, 1, De), kv_index), per_page,
+                 (pl.BlockSpec((1, page, De), kv_index), per_page,
                   itemsize)]
     out_spec = pl.BlockSpec((1, 1, G, Dv), head_index)
     byt = B * n_cols * 4 + B * 4            # scalar-prefetch table + q_pos
@@ -109,8 +116,20 @@ def _spec_plan(B: int, Hq: int, Hkv: int, page: int, n_cols: int,
     return [s for s, _, _ in plan], out_spec, byt
 
 
-def paged_attention_cost(q, k, v, table, q_extra=None,
-                         interpret: bool = True) -> pl.CostEstimate:
+def _lane_pad(dim: int, Hkv: int, interpret: bool) -> int:
+    """Zero columns that make a head's ``dim``-wide lane block of the
+    flattened ``(N, page, Hkv*dim)`` pool Mosaic-legal: a block's last
+    dim must be a multiple of 128 or the whole axis.  With one kv head
+    the block IS the whole axis, so only multi-head pools with a
+    non-lane-multiple head dim pay the pad (a copy of the pool);
+    interpret mode never pads."""
+    if interpret or Hkv == 1:
+        return 0
+    return -dim % 128
+
+
+def paged_attention_cost(q, k, v, table, q_extra=None, *,
+                         interpret: bool) -> pl.CostEstimate:
     """Analytic cost of one paged-decode call — the DMA schedule the
     grid actually executes, derived from the SAME spec plan the kernel
     is built from (``_spec_plan``): every table column's K/V (+rope)
@@ -118,16 +137,16 @@ def paged_attention_cost(q, k, v, table, q_extra=None,
     (slot, kv head), scalar table/q_pos in SMEM.  No gathered copy, so
     no other HBM term exists.  Pass the same ``interpret`` flag as the
     call being costed: compiled (Mosaic) mode lane-pads head dims to
-    128, so its blocks — and therefore its DMA bytes — are wider than
-    interpret mode's."""
+    128 where several kv heads share the pool's lane axis
+    (``_lane_pad``), so its blocks — and therefore its DMA bytes — can be
+    wider than interpret mode's."""
     B, _, Hq, D = q.shape
     N, page, Hkv, Dv = v.shape
     n_cols = table.shape[1]
     De = 0 if q_extra is None else q_extra.shape[-1]
-    if not interpret:                      # mirror the fwd lane padding
-        D += -D % 128
-        Dv += -Dv % 128
-        De += -De % 128 if De else 0
+    D += _lane_pad(D, Hkv, interpret)      # mirror the fwd lane padding
+    Dv += _lane_pad(Dv, Hkv, interpret)
+    De += _lane_pad(De, Hkv, interpret)
     _, _, byt = _spec_plan(B, Hq, Hkv, page, n_cols, D, Dv, De,
                            q.dtype.itemsize)
     T = n_cols * page
@@ -141,8 +160,8 @@ def _paged_decode_kernel(tab_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref,
                          softcap: float, n_cols: int, has_extra: bool):
     """One (slot*kv_head, page_column) grid step.
 
-    Blocks: q (1, 1, G, D); k (1, page, 1, D); v (1, page, 1, Dv);
-    pos (1, page); [qe (1, 1, G, De); ke (1, page, 1, De)];
+    Blocks: q (1, 1, G, D); k (1, page, D); v (1, page, Dv);
+    pos (1, 1, page); [qe (1, 1, G, De); ke (1, page, De)];
     out (1, 1, G, Dv); scratch m/l (G, 1), acc (G, Dv) — all f32.
     """
     if has_extra:
@@ -162,30 +181,30 @@ def _paged_decode_kernel(tab_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref,
     blk = tab_ref[b, ic]                                 # -1 = unallocated
     q_pos = qpos_ref[b]                                  # -1 = idle slot
     q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (page, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (page, Dv)
+    k = k_ref[0].astype(jnp.float32)                     # (page, D)
+    v = v_ref[0].astype(jnp.float32)                     # (page, Dv)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, page)
     if has_extra:
         qe = qe_ref[0, 0].astype(jnp.float32) * scale    # (G, De)
-        ke = ke_ref[0, :, 0].astype(jnp.float32)         # (page, De)
+        ke = ke_ref[0].astype(jnp.float32)               # (page, De)
         s = s + jax.lax.dot_general(qe, ke, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
     if softcap > 0.0:
         s = softcap * jnp.tanh(s / softcap)
 
-    pj = pos_ref[0]                                      # (page,) int32
+    pj = pos_ref[0]                                      # (1, page) int32
     ok = (blk >= 0) & (pj >= 0) & (pj <= q_pos)          # causal + validity
     if window > 0:
         ok &= pj > q_pos - window
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     # fully-masked pages: exp(NEG_INF - NEG_INF) = 1 — zero it like the
     # scan path so unallocated pages carry exactly-zero probability mass
-    p = jnp.where(ok[None, :], jnp.exp(s - m_new), 0.0)
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
@@ -204,7 +223,7 @@ def paged_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
                         softcap: float = 0.0,
                         q_extra: jax.Array | None = None,
                         k_extra: jax.Array | None = None,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """Paged single-token decode attention.
 
     q: (B, 1, Hq, D); k: (N, page, Hkv, D); v: (N, page, Hkv, Dv);
@@ -230,11 +249,13 @@ def paged_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
     cost = paged_attention_cost(q, k, v, table, q_extra,
                                 interpret=interpret)
 
-    # lane padding for MXU/VPU tiles when compiling through Mosaic —
-    # exact (zero columns contribute nothing to either dot), skipped in
-    # interpret mode where it would only waste host flops
-    d_pad = 0 if interpret else -D % 128
-    dv_pad = 0 if interpret else -Dv % 128
+    # Mosaic views: each pool as (N, page, Hkv*D) and the positions as
+    # (N, 1, page) — free reshapes — so a head's page is a (page, D)
+    # block; q as (B, Hkv, G, D), whose (G, D) blocks are whole.  Lane
+    # padding (exact: zero columns add nothing to either dot) only where
+    # the head dim alone cannot make a legal block (``_lane_pad``)
+    d_pad = _lane_pad(D, Hkv, interpret)
+    dv_pad = _lane_pad(Dv, Hkv, interpret)
     qh = q.reshape(B, Hkv, G, D)
     if d_pad:
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, 0), (0, d_pad)))
@@ -243,18 +264,21 @@ def paged_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dv_pad)))
     Dp, Dvp = D + d_pad, Dv + dv_pad
 
-    operands = [qh, k, v, pos]
+    def lanes(pool):
+        return pool.reshape(N, page, -1)
+
+    operands = [qh, lanes(k), lanes(v), pos.reshape(N, 1, page)]
     Dep = 0
     if has_extra:
         De = q_extra.shape[-1]
-        de_pad = 0 if interpret else -De % 128
+        de_pad = _lane_pad(De, Hkv, interpret)
         qe = q_extra.reshape(B, Hkv, G, De)
         ke = k_extra
         if de_pad:
             qe = jnp.pad(qe, ((0, 0), (0, 0), (0, 0), (0, de_pad)))
             ke = jnp.pad(ke, ((0, 0), (0, 0), (0, 0), (0, de_pad)))
         Dep = De + de_pad
-        operands += [qe, ke]
+        operands += [qe, lanes(ke)]
 
     in_specs, out_spec, _ = _spec_plan(B, Hq, Hkv, page, n_cols, Dp,
                                        Dvp, Dep, q.dtype.itemsize)
@@ -278,7 +302,7 @@ def paged_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dvp), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=cost,
         interpret=interpret,

@@ -34,7 +34,7 @@ def _dequant_kernel(q_ref, s_ref, x_ref):
 
 
 def int8_quantize(x: jax.Array, *, block: int = DEFAULT_BLOCK,
-                  rows_tile: int = DEFAULT_ROWS, interpret: bool = True):
+                  rows_tile: int = DEFAULT_ROWS, interpret: bool):
     """x: any shape -> (codes int8 (n_rows, block), scales f32 (n_rows, 1)).
     Rows are contiguous ``block``-element groups of the flattened input."""
     flat = x.reshape(-1)
@@ -61,7 +61,7 @@ def int8_quantize(x: jax.Array, *, block: int = DEFAULT_BLOCK,
 
 
 def int8_dequantize(q: jax.Array, scales: jax.Array, shape, dtype=jnp.float32,
-                    *, rows_tile: int = DEFAULT_ROWS, interpret: bool = True):
+                    *, rows_tile: int = DEFAULT_ROWS, interpret: bool):
     rows, block = q.shape
     row_pad = -rows % rows_tile
     qb = jnp.pad(q, ((0, row_pad), (0, 0)))

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 DEFAULT_CHUNK = 64
 DEFAULT_DI_BLOCK = 512
 
@@ -59,7 +57,7 @@ def _mamba_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, h_scr, *,
 def mamba_scan(x: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
                a: jax.Array, *, chunk: int = DEFAULT_CHUNK,
                di_block: int = DEFAULT_DI_BLOCK,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """x, dt: (B, S, di); b, c: (B, S, ds); a: (di, ds) [negative].
     Returns y: (B, S, di) with y_t = C_t · h_t,
     h_t = exp(dt_t·A)·h_{t-1} + (dt_t·x_t)·B_t."""
@@ -92,7 +90,7 @@ def mamba_scan(x: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, bdi), lambda ib, idi, ic: (ib, ic, idi)),
         out_shape=jax.ShapeDtypeStruct((B, Sp, dip), x.dtype),
         scratch_shapes=[pltpu.VMEM((bdi, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, dtp, bp, cp, ap)
@@ -130,7 +128,7 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
 
 def rwkv_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
               u: jax.Array, *, chunk: int = DEFAULT_CHUNK,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """r,k,v,w: (B, S, H, hd); u: (H, hd).  Returns o: (B, S, H, hd) with
     o_t = r_t·(S_{t-1} + diag(u)·k_t v_tᵀ), S_t = diag(w_t)·S_{t-1} + k_t v_tᵀ."""
     B, S, H, hd = r.shape
@@ -155,7 +153,7 @@ def rwkv_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, hd), lambda ib, ic: (ib, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sp, hd), r.dtype),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rp, kp, vp, wp, jnp.tile(u, (B, 1)).reshape(B * H, hd))

@@ -11,12 +11,20 @@ touches jax device state (the dry-run must set XLA_FLAGS first).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: sharding is propagated by GSPMD
+    from ``with_sharding_constraint`` and the shard_map specs, which the
+    default Explicit axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(n_stages: int = 0):
@@ -24,7 +32,7 @@ def make_host_mesh(n_stages: int = 0):
     pipelined-executor example, not by the dry-run."""
     n = len(jax.devices())
     stages = n_stages or n
-    return jax.make_mesh((stages,), ("stage",))
+    return _auto_mesh((stages,), ("stage",))
 
 
 def data_axes(mesh) -> tuple:

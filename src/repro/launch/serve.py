@@ -1,5 +1,10 @@
-"""Serving launcher: chunked-prefill continuous-batching engine over a
-smoke config (CPU) — the production-mesh serve path is proven by dryrun.py.
+"""Serving launcher: chunked-prefill continuous-batching engine.
+
+``--smoke`` (the default) serves the reduced same-family config, which
+runs anywhere; ``--chip`` serves the architecture's chip-share preset
+(``configs.<arch>.CHIP``: published widths, depth cut to one chip's
+share of a stated deployment), which needs a TPU.  The production-mesh
+serve path is proven by dryrun.py.
 
 The engine runs exactly two steady-state jitted shapes: the chunked-
 prefill step ``(slots, chunk)`` and the decode tick ``(slots, 1)``;
@@ -51,6 +56,8 @@ held so imports and re-admissions dedup against them.
         --requests 8 --max-new 16 --slots 4 --chunk 16 --page-size 16
     PYTHONPATH=src python -m repro.launch.serve --replicas 3 \
         --devices rtx4090,rtx3080 --standby 1 --requests 12
+    PYTHONPATH=src python -m repro.launch.serve --chip --kernel \
+        --slots 8 --cache-len 2048 --chunk 128 --prefix-tokens 256
 """
 from __future__ import annotations
 
@@ -59,16 +66,22 @@ import time
 
 import jax
 
-from repro.configs import ALL_ARCHS, get_smoke_config
+from repro.configs import ALL_ARCHS, get_chip_config, get_smoke_config
 from repro.core.perfmodel import DEVICE_CATALOG
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import init_params
 from repro.serve.engine import Request, ServingEngine
 from repro.serve.router import FleetRouter, sim_node
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ALL_ARCHS), default="qwen3-8b")
+    ap.add_argument("--smoke", action="store_true", default=True,
+                    help="reduced same-family config (default)")
+    ap.add_argument("--chip", dest="smoke", action="store_false",
+                    help="chip-share preset at published widths "
+                         "(configs.<arch>.CHIP; needs a TPU)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
@@ -98,8 +111,8 @@ def main():
                          "to every request (exercises prefix sharing)")
     ap.add_argument("--kernel", action="store_true",
                     help="decode attention through the fused Pallas "
-                         "paged-decode kernel (paged mode only; interpret "
-                         "mode on CPU, Mosaic with REPRO_PALLAS_COMPILE=1)")
+                         "paged-decode kernel (paged mode only; compiled "
+                         "through Mosaic on a TPU, interpreted on the CPU)")
     ap.add_argument("--temperature", type=float, default=0.0,
                     help="per-request sampling temperature (0 = greedy)")
     ap.add_argument("--top-p", type=float, default=1.0,
@@ -170,10 +183,21 @@ def main():
                     help="skip ahead-of-traffic compilation of the two "
                          "engine shapes")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_smoke_config(args.arch)
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
+
+def model_config(args):
+    return (get_smoke_config(args.arch) if args.smoke
+            else get_chip_config(args.arch))
+
+
+def engine_builder(args, cfg):
+    """Seeded random weights for ``cfg`` and a zero-argument builder of
+    engines over them (fleet replicas share the one parameter pytree).
+    The init is jitted so each weight is drawn straight into bf16,
+    without a float32 copy of the largest matrices on the device."""
+    params = jax.jit(init_params, static_argnums=1)(
+        jax.random.PRNGKey(args.seed), cfg)
 
     def build_engine():
         return ServingEngine(params, cfg, slots=args.slots,
@@ -183,6 +207,14 @@ def main():
                              use_kernel=args.kernel, seed=args.seed,
                              share_prefix=args.prefix_share,
                              hold_pages=args.hold_pages)
+    return build_engine
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    use_compile_cache()
+    cfg = model_config(args)
+    build_engine = engine_builder(args, cfg)
 
     if args.replicas > 1:
         serve_fleet(args, cfg, build_engine)

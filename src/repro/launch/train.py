@@ -13,6 +13,7 @@ import argparse
 
 from repro.configs import ALL_ARCHS, get_config, get_smoke_config
 from repro.data.synthetic import SyntheticConfig, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.train.trainer import TrainConfig, Trainer
 
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     loader = SyntheticLM(SyntheticConfig(

@@ -1069,11 +1069,13 @@ class ServingEngine:
     def _table_arg(self):
         """The block-table step operand: one array for single-kind
         engines, per-cache-kind {"attn", "swa"} tables when the pools
-        have split block-id spaces."""
+        have split block-id spaces.  Copied (``jnp.array``): the host
+        mutates these tables in place while a dispatched step may still
+        be reading them, and ``jnp.asarray`` can alias host memory."""
         if self.paged and self._has_swa:
-            return {"attn": jnp.asarray(self._table),
-                    "swa": jnp.asarray(self._table_swa)}
-        return jnp.asarray(self._table)
+            return {"attn": jnp.array(self._table),
+                    "swa": jnp.array(self._table_swa)}
+        return jnp.array(self._table)
 
     # -- occupancy / fleet hooks (read by serve.router.FleetRouter) ------
 
@@ -1504,7 +1506,9 @@ class ServingEngine:
 
     def _call_step(self, toks: np.ndarray, pos: np.ndarray):
         """One jitted engine step; host-side operands (numpy) convert to
-        device arrays ONCE here.  The per-slot PRNG keys are counter-based
+        device arrays ONCE here, as copies — the per-slot sampling
+        vectors are mutated in place by admission and release while the
+        step runs asynchronously.  The per-slot PRNG keys are counter-based
         (slot seed, step counter), so sampling streams are deterministic
         and never leave host control."""
         keys = np.empty((self.slots, 2), np.uint32)
@@ -1513,10 +1517,10 @@ class ServingEngine:
         keys[:, 1] = np.uint32(self._step_seq)
         self._step_seq += 1
         nxt, self.caches, self._seen = self._step_fn(
-            self.params, self.caches, self._seen, jnp.asarray(toks),
-            jnp.asarray(pos), self._table_arg(), jnp.asarray(keys),
-            jnp.asarray(self._temp), jnp.asarray(self._topp),
-            jnp.asarray(self._topk), jnp.asarray(self._reppen))
+            self.params, self.caches, self._seen, jnp.array(toks),
+            jnp.array(pos), self._table_arg(), jnp.array(keys),
+            jnp.array(self._temp), jnp.array(self._topp),
+            jnp.array(self._topk), jnp.array(self._reppen))
         return nxt, self.caches
 
     def _admit(self) -> None:
