@@ -300,6 +300,7 @@ def paged_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dvp), q.dtype),
         compiler_params=pltpu.CompilerParams(

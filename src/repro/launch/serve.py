@@ -248,7 +248,8 @@ def main(argv=None):
           f"{dt:.2f}s ({toks/dt:.1f} tok/s, slots={args.slots}, {mode})")
     print(f"  engine calls: {st['prefill_calls']} prefill (chunk="
           f"{engine.chunk}) + {st['decode_calls']} decode ticks, "
-          f"{st['admitted']} admissions, {st['backpressure']} backpressure")
+          f"{st['admitted']} admissions, {st['backpressure']} backpressure, "
+          f"{st['step_compiles']} step programs compiled or loaded")
     if engine._can_share:
         print(f"  prefix sharing: {st['shared_pages']} pages attached "
               f"({st['shared_tokens']} prompt tokens skipped prefill), "

@@ -11,11 +11,14 @@ engine-wide worst-case ``cache_len`` per slot).
 multi-pod dry-run lowers for the ``prefill_32k`` / ``decode_32k`` /
 ``long_500k`` input shapes; ``make_engine_step`` is the single
 masked-slot step function behind ``ServingEngine`` (chunked prefill and
-decode tick are the same callable at two shapes).
+decode tick are the same function at two shapes, jitted under two names:
+``engine_step_prefill`` and ``engine_step_decode``).
 """
 from __future__ import annotations
 
+import time
 import zlib
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Callable, Dict, List, Optional, Tuple
@@ -23,6 +26,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.config import ATTN, MAMBA, RWKV, SWA, ModelConfig
 from repro.models.layers import NEG_INF, swa_ring_blocks
@@ -33,6 +37,17 @@ Array = jax.Array
 # pool leaves of paged attention-family caches (block-indexed, shared
 # across slots); everything else in a cache pytree is per-slot state
 POOL_LEAVES = ("k", "v", "pos", "ckv", "krope")
+
+# host spans of the serving loop (``jax.profiler.TraceAnnotation``): they
+# record only while a profiler trace runs, on the device trace's clock,
+# and cost one inactive TraceMe each otherwise
+SPAN_TICK = "engine.tick"           # all of ServingEngine.tick()
+SPAN_ADMIT = "engine.admit"         # one request's admission
+SPAN_PREFILL = "engine.prefill"     # one chunk: inputs + step dispatch
+SPAN_DECODE = "engine.decode"       # the decode step: inputs + dispatch
+SPAN_READBACK = "engine.readback"   # waiting for and copying step tokens
+SPAN_RELEASE = "engine.release"     # freeing and scrubbing a slot's pages
+SPAN_COMPILE = "engine.compile"     # first dispatch of a step shape
 
 
 def make_prefill_step(cfg: ModelConfig, *, kv_chunk: int = 1024) -> Callable:
@@ -108,8 +123,8 @@ def make_engine_step(cfg: ModelConfig, *, kv_chunk: int = 1024,
     (B,), top_k (B,) int32, rep_penalty (B,)) ->
     (next-token ids (B,1) int32, caches, seen).
 
-    The one step function behind the continuous batcher: the SAME jitted
-    callable serves chunked prefill (S = chunk) and the batched decode
+    The one step function behind the continuous batcher: the SAME
+    function serves chunked prefill (S = chunk) and the batched decode
     tick (S = 1, which statically selects the single-token cache paths —
     absorbed MLA etc.).  Rows/entries with position -1 are cache/state
     no-ops, so idle slots ride along for free.  Only the LAST position is
@@ -171,6 +186,21 @@ def make_engine_step(cfg: ModelConfig, *, kv_chunk: int = 1024,
             lambda: greedy)
         return tok, caches, seen
     return engine_step
+
+
+def _stamp() -> float:
+    """The host clock of the ``Request`` stamps.  Replay stays bitwise:
+    the stamps are recorded for measurement and no decision reads them."""
+    return time.perf_counter()  # repro-lint: disable=DET001 (stamps only)
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``, which a jit of it gives its compiled program
+    (``jit_<name>`` in HLO dumps and profiler traces)."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 def sample(logits: Array, key, temperature: float = 0.0) -> Array:
@@ -248,6 +278,14 @@ class Request:
     # the decode that first produced that KV) and restores ``generated``
     # from it, so only tokens decoded since the snapshot are re-decoded.
     resume_tokens: Optional[List[int]] = None
+    # host-clock stamps (``_stamp``, None until set): submit() stamps
+    # submitted_at; admission stamps admitted_at when it pops the
+    # request off the queue and prefilled_at once its first token is on
+    # the host.  A requeue (drain, preemption) clears the last two.
+    # Observations only: no decision reads them, and equality ignores them
+    submitted_at: Optional[float] = field(default=None, compare=False)
+    admitted_at: Optional[float] = field(default=None, compare=False)
+    prefilled_at: Optional[float] = field(default=None, compare=False)
 
 
 class BlockAllocator:
@@ -704,7 +742,14 @@ class ServingEngine:
     ``stats`` counts jitted forward calls (``prefill_calls`` /
     ``decode_calls``) — the admission cost of an S-token prompt is
     ``ceil(S/chunk)`` calls, which tests and benchmarks rely on — plus
-    ``admitted`` and paged-pool ``backpressure`` events.
+    ``admitted`` and paged-pool ``backpressure`` events, and
+    ``step_compiles``: the first dispatch of each step program and shape
+    (``engine_step_prefill`` at every chunk width, ``engine_step_decode``),
+    where JIT compiles or loads from the persistent cache.  Each
+    ``Request`` carries host-clock stamps ``submitted_at`` /
+    ``admitted_at`` / ``prefilled_at`` (queue wait and prefill apart).
+    The serving loop runs inside ``engine.*`` profiler spans (``SPAN_*``
+    above), so a trace splits the host's share of a tick by phase.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, slots: int = 4,
@@ -782,9 +827,12 @@ class ServingEngine:
         donate = jax.default_backend() != "cpu"
         dn = dict(donate_argnums=(1, 2)) if donate else {}
         d0 = dict(donate_argnums=(0,)) if donate else {}
-        self._step_fn = jax.jit(
-            make_engine_step(cfg, paged=paged,
-                             use_kernel=self.use_kernel), **dn)
+        # one step function, jitted under two names so a trace tells
+        # chunked prefill and the decode tick apart
+        step = make_engine_step(cfg, paged=paged, use_kernel=self.use_kernel)
+        self._prefill_fn = jax.jit(_named(step, "engine_step_prefill"), **dn)
+        self._decode_fn = jax.jit(_named(step, "engine_step_decode"), **dn)
+        self._step_shapes: set = set()     # (decode?, width) dispatched
         self._reset_fn = jax.jit(partial(_clear_slot, skip_pools=paged), **d0)
         self._clear_blocks_fn = jax.jit(make_clear_blocks(cfg), **d0)
         self._copy_block_fn = jax.jit(_copy_block, **d0)
@@ -807,7 +855,7 @@ class ServingEngine:
                       "shared_tokens": 0, "cow_copies": 0, "preempted": 0,
                       "exported": 0, "imported": 0, "import_rejects": 0,
                       "imported_pages": 0, "deduped_pages": 0,
-                      "resumed_tokens": 0}
+                      "resumed_tokens": 0, "step_compiles": 0}
         self._seed = seed
         self._step_seq = 0
         self._admit_seq = 0
@@ -1059,12 +1107,13 @@ class ServingEngine:
         clear the slot, free + scrub its pages, and restore the greedy
         sampling defaults so an idle slot can't keep the
         all-greedy/no-penalty fast paths (lax.cond) switched off."""
-        self.active[s] = None
-        self._free_slot_blocks(s)
-        self._temp[s] = 0.0
-        self._topp[s] = 1.0
-        self._topk[s] = 0
-        self._reppen[s] = 1.0
+        with TraceAnnotation(SPAN_RELEASE):
+            self.active[s] = None
+            self._free_slot_blocks(s)
+            self._temp[s] = 0.0
+            self._topp[s] = 1.0
+            self._topk[s] = 0
+            self._reppen[s] = 1.0
 
     def _table_arg(self):
         """The block-table step operand: one array for single-kind
@@ -1404,6 +1453,7 @@ class ServingEngine:
             req.generated = []
             req.pending = -1
             req.done = False
+            req.admitted_at = req.prefilled_at = None
             req.prefix_digests = self.prefix_digests(req.prompt)
         return out
 
@@ -1431,6 +1481,7 @@ class ServingEngine:
         req.generated = []
         req.pending = -1
         req.done = False
+        req.admitted_at = req.prefilled_at = None
         req.prefix_digests = self.prefix_digests(req.prompt)
         self.stats["preempted"] += 1
         return req
@@ -1476,6 +1527,7 @@ class ServingEngine:
                     f"ServingEngine: request {req.req_id} needs {need} cache "
                     f"pages but the pool has only {self.num_blocks} — it "
                     f"could never be admitted (raise num_blocks)")
+        req.submitted_at = _stamp()
         self.queue.append(req)
 
     def warmup(self) -> None:
@@ -1483,10 +1535,10 @@ class ServingEngine:
         prefill step (slots, chunk) and the decode tick (slots, 1).  Runs
         them with every position masked (-1), which is a cache no-op, so
         warmup never perturbs engine state."""
-        for C in sorted({self.chunk, 1}):
+        for C, decode in ((self.chunk, False), (1, True)):
             toks = np.zeros((self.slots, C), np.int32)
             pos = np.full((self.slots, C), -1, np.int32)
-            _, self.caches = self._call_step(toks, pos)
+            _, self.caches = self._call_step(toks, pos, decode)
         # compile the reset against a FREE slot only (resetting it is
         # harmless — admission resets again); never touch a live one
         free = [s for s in range(self.slots) if self.active[s] is None]
@@ -1504,23 +1556,36 @@ class ServingEngine:
 
     # -- the serving loop ------------------------------------------------
 
-    def _call_step(self, toks: np.ndarray, pos: np.ndarray):
-        """One jitted engine step; host-side operands (numpy) convert to
-        device arrays ONCE here, as copies — the per-slot sampling
-        vectors are mutated in place by admission and release while the
-        step runs asynchronously.  The per-slot PRNG keys are counter-based
-        (slot seed, step counter), so sampling streams are deterministic
-        and never leave host control."""
+    def _call_step(self, toks: np.ndarray, pos: np.ndarray, decode: bool):
+        """One jitted engine step (``engine_step_decode`` for the decode
+        tick, ``engine_step_prefill`` for a prompt chunk); host-side
+        operands (numpy) convert to device arrays ONCE here, as copies —
+        the per-slot sampling vectors are mutated in place by admission
+        and release while the step runs asynchronously.  The per-slot
+        PRNG keys are counter-based (slot seed, step counter), so
+        sampling streams are deterministic and never leave host control.
+        The first dispatch of each program and width runs inside an
+        ``engine.compile`` span and counts in ``stats["step_compiles"]``."""
         keys = np.empty((self.slots, 2), np.uint32)
         keys[:, 0] = np.arange(self._seed, self._seed + self.slots,
                                dtype=np.uint32)
         keys[:, 1] = np.uint32(self._step_seq)
         self._step_seq += 1
-        nxt, self.caches, self._seen = self._step_fn(
-            self.params, self.caches, self._seen, jnp.array(toks),
-            jnp.array(pos), self._table_arg(), jnp.array(keys),
-            jnp.array(self._temp), jnp.array(self._topp),
-            jnp.array(self._topk), jnp.array(self._reppen))
+        args = (jnp.array(toks), jnp.array(pos), self._table_arg(),
+                jnp.array(keys), jnp.array(self._temp), jnp.array(self._topp),
+                jnp.array(self._topk), jnp.array(self._reppen))
+        shape = (decode, toks.shape[1])
+        first = shape not in self._step_shapes
+        if first:
+            self._step_shapes.add(shape)
+            self.stats["step_compiles"] += 1
+        with TraceAnnotation(SPAN_COMPILE) if first else nullcontext():
+            if decode:
+                nxt, self.caches, self._seen = self._decode_fn(
+                    self.params, self.caches, self._seen, *args)
+            else:
+                nxt, self.caches, self._seen = self._prefill_fn(
+                    self.params, self.caches, self._seen, *args)
         return nxt, self.caches
 
     def _admit(self) -> None:
@@ -1543,113 +1608,134 @@ class ServingEngine:
         which is what raises peak concurrency at equal pool memory."""
         for s in range(self.slots):
             if self.active[s] is None and self.queue:
-                req = self.queue[0]
-                # snapshot restore: prefill prompt + resume tokens as ONE
-                # extended prompt (chunked prefill writes KV bitwise-equal
-                # to the decode that first produced it), then restore
-                # ``generated`` below — only tokens decoded after the
-                # router's snapshot are re-decoded
-                resume = list(req.resume_tokens or ())
-                full = req.prompt + resume
-                S = len(full)
-                # total logical length stays prompt + max_new: resumed
-                # tokens count against the generation budget
-                total = S + req.max_new - len(resume)
-                shared_tok, hits, partial = (
-                    self._match_prefix(full) if self._can_share
-                    else (0, [], None))
-                start = min(shared_tok, S - 1)
-                if self.paged:
-                    # reserve only unshared pages — but a shared page the
-                    # tail will write into (the partial hit, or the last
-                    # full hit when the whole prompt matched) still needs
-                    # a private page for its copy-on-write
-                    untouched = sum(1 for (i, _) in hits
-                                    if (i + 1) * self.page_size <= start)
-                    need = self._blocks_for(total) - untouched
-                    if not self._alloc.reserve(need):
-                        self.stats["backpressure"] += 1
+                with TraceAnnotation(SPAN_ADMIT):
+                    if not self._admit_into(s):
                         break          # FIFO: later requests wait too
-                    self._slot_reserved[s] = need
-                    self._drain_scrub()
-                    need_swa = self._blocks_for_swa(total)
-                    if need_swa:
-                        ok = self._alloc_swa.reserve(need_swa)
-                        assert ok   # exact-fit pool: slots * ring_blocks
-                        self._slot_reserved_swa[s] = need_swa
-                self.queue.pop(0)
-                self.active[s] = req
-                self._admit_seq += 1
-                self._admitted_at[s] = self._admit_seq
-                self.caches = self._reset_fn(self.caches, s)
-                self._seen = self._clear_seen_fn(self._seen, s)
-                self._temp[s] = req.temperature
-                self._topp[s] = req.top_p
-                self._topk[s] = req.top_k
-                self._reppen[s] = req.rep_penalty
-                for (c, b) in hits:
-                    self._table[s, c] = b
-                    self._alloc.share(b)
-                    self._slot_shared[s].add(c)
-                if partial is not None:
-                    c, b, _ = partial
-                    self._table[s, c] = b
-                    self._alloc.share(b)
-                    self._slot_shared[s].add(c)
-                self.stats["shared_pages"] += \
-                    len(hits) + (1 if partial else 0)
-                self.stats["shared_tokens"] += start
-                prompt = np.asarray(full, np.int32)
-                nxt = None
-                for c0 in range(start, S, self.chunk):
-                    piece = prompt[c0:c0 + self.chunk]
-                    C = len(piece)
-                    self._ensure_blocks(s, c0, c0 + C - 1)
-                    toks = np.zeros((self.slots, C), np.int32)
-                    toks[s] = piece
-                    pos = np.full((self.slots, C), -1, np.int32)
-                    pos[s] = np.arange(c0, c0 + C, dtype=np.int32)
-                    nxt, self.caches = self._call_step(toks, pos)
-                    self.stats["prefill_calls"] += 1
-                if self._can_share:
-                    self._register_prefix(s, full)
-                self.positions[s] = S
-                # the extended prompt's last logits ARE the decode logits
-                # at that position (chunked-prefill parity), so greedy
-                # resume continues exactly where the snapshot left off
-                req.pending = int(nxt[s, -1])
-                if resume:
-                    req.generated = resume
-                    req.resume_tokens = None
-                    self.stats["resumed_tokens"] += len(resume)
-                self.stats["admitted"] += 1
+
+    def _admit_into(self, s: int) -> bool:
+        """Admit the queue's head into the free slot ``s``, through to its
+        first token on the host; False where the pool backpressures."""
+        req = self.queue[0]
+        # snapshot restore: prefill prompt + resume tokens as ONE
+        # extended prompt (chunked prefill writes KV bitwise-equal
+        # to the decode that first produced it), then restore
+        # ``generated`` below — only tokens decoded after the
+        # router's snapshot are re-decoded
+        resume = list(req.resume_tokens or ())
+        full = req.prompt + resume
+        S = len(full)
+        # total logical length stays prompt + max_new: resumed
+        # tokens count against the generation budget
+        total = S + req.max_new - len(resume)
+        shared_tok, hits, partial = (
+            self._match_prefix(full) if self._can_share
+            else (0, [], None))
+        start = min(shared_tok, S - 1)
+        if self.paged:
+            # reserve only unshared pages — but a shared page the
+            # tail will write into (the partial hit, or the last
+            # full hit when the whole prompt matched) still needs
+            # a private page for its copy-on-write
+            untouched = sum(1 for (i, _) in hits
+                            if (i + 1) * self.page_size <= start)
+            need = self._blocks_for(total) - untouched
+            if not self._alloc.reserve(need):
+                self.stats["backpressure"] += 1
+                return False
+            self._slot_reserved[s] = need
+            self._drain_scrub()
+            need_swa = self._blocks_for_swa(total)
+            if need_swa:
+                ok = self._alloc_swa.reserve(need_swa)
+                assert ok   # exact-fit pool: slots * ring_blocks
+                self._slot_reserved_swa[s] = need_swa
+        self.queue.pop(0)
+        req.admitted_at = _stamp()
+        self.active[s] = req
+        self._admit_seq += 1
+        self._admitted_at[s] = self._admit_seq
+        self.caches = self._reset_fn(self.caches, s)
+        self._seen = self._clear_seen_fn(self._seen, s)
+        self._temp[s] = req.temperature
+        self._topp[s] = req.top_p
+        self._topk[s] = req.top_k
+        self._reppen[s] = req.rep_penalty
+        for (c, b) in hits:
+            self._table[s, c] = b
+            self._alloc.share(b)
+            self._slot_shared[s].add(c)
+        if partial is not None:
+            c, b, _ = partial
+            self._table[s, c] = b
+            self._alloc.share(b)
+            self._slot_shared[s].add(c)
+        self.stats["shared_pages"] += \
+            len(hits) + (1 if partial else 0)
+        self.stats["shared_tokens"] += start
+        prompt = np.asarray(full, np.int32)
+        nxt = None
+        for c0 in range(start, S, self.chunk):
+            with TraceAnnotation(SPAN_PREFILL):
+                piece = prompt[c0:c0 + self.chunk]
+                C = len(piece)
+                self._ensure_blocks(s, c0, c0 + C - 1)
+                toks = np.zeros((self.slots, C), np.int32)
+                toks[s] = piece
+                pos = np.full((self.slots, C), -1, np.int32)
+                pos[s] = np.arange(c0, c0 + C, dtype=np.int32)
+                nxt, self.caches = self._call_step(toks, pos, False)
+                self.stats["prefill_calls"] += 1
+        if self._can_share:
+            self._register_prefix(s, full)
+        self.positions[s] = S
+        # the extended prompt's last logits ARE the decode logits
+        # at that position (chunked-prefill parity), so greedy
+        # resume continues exactly where the snapshot left off
+        with TraceAnnotation(SPAN_READBACK):
+            req.pending = int(nxt[s, -1])
+        req.prefilled_at = _stamp()
+        if resume:
+            req.generated = resume
+            req.resume_tokens = None
+            self.stats["resumed_tokens"] += len(resume)
+        self.stats["admitted"] += 1
+        return True
 
     def tick(self) -> int:
         """One engine iteration: feed each active slot's pending token,
         emit it, and compute the next — a single jitted decode step over
         all slots.  Returns #active slots."""
-        self._admit()
-        act = [s for s in range(self.slots) if self.active[s] is not None]
-        if not act:
-            return 0
-        toks = np.zeros((self.slots, 1), np.int32)
-        pos = np.full((self.slots, 1), -1, np.int32)
-        for s in act:
-            self._ensure_blocks(s, self.positions[s], self.positions[s])
-            toks[s, 0] = self.active[s].pending
-            pos[s, 0] = self.positions[s]
-        nxt, self.caches = self._call_step(toks, pos)
-        self.stats["decode_calls"] += 1
-        for s in act:
-            req = self.active[s]
-            req.generated.append(req.pending)
-            req.pending = int(nxt[s, 0])
-            self.positions[s] += 1
-            if len(req.generated) >= req.max_new:
-                req.done = True
-                self.finished.append(req)
-                self._release_slot(s)
-        return len(act)
+        with TraceAnnotation(SPAN_TICK):
+            self._admit()
+            act = [s for s in range(self.slots)
+                   if self.active[s] is not None]
+            if not act:
+                return 0
+            with TraceAnnotation(SPAN_DECODE):
+                toks = np.zeros((self.slots, 1), np.int32)
+                pos = np.full((self.slots, 1), -1, np.int32)
+                for s in act:
+                    self._ensure_blocks(s, self.positions[s],
+                                        self.positions[s])
+                    toks[s, 0] = self.active[s].pending
+                    pos[s, 0] = self.positions[s]
+                nxt, self.caches = self._call_step(toks, pos, True)
+                self.stats["decode_calls"] += 1
+            # every token first, then the releases: the readback span
+            # holds only the host's wait for the step and its copies
+            with TraceAnnotation(SPAN_READBACK):
+                for s in act:
+                    req = self.active[s]
+                    req.generated.append(req.pending)
+                    req.pending = int(nxt[s, 0])
+                    self.positions[s] += 1
+            for s in act:
+                req = self.active[s]
+                if len(req.generated) >= req.max_new:
+                    req.done = True
+                    self.finished.append(req)
+                    self._release_slot(s)
+            return len(act)
 
     def run(self, max_ticks: int = 10_000) -> List[Request]:
         for _ in range(max_ticks):

@@ -172,21 +172,27 @@ def test_admission_call_count(S, chunk):
     params = init_params(jax.random.PRNGKey(3), cfg)
     eng = ServingEngine(params, cfg, slots=2, cache_len=64, chunk=chunk)
     calls = []
-    orig = eng._step_fn
-    def counting(p, c, seen, toks, pos, *rest):
-        calls.append(tuple(toks.shape))
-        return orig(p, c, seen, toks, pos, *rest)
-    eng._step_fn = counting
+
+    def counting(kind, orig):
+        def f(p, c, seen, toks, pos, *rest):
+            calls.append((kind, tuple(toks.shape)))
+            return orig(p, c, seen, toks, pos, *rest)
+        return f
+    eng._prefill_fn = counting("prefill", eng._prefill_fn)
+    eng._decode_fn = counting("decode", eng._decode_fn)
     eng.submit(Request(0, list(range(1, S + 1)), max_new=2))
     eng._admit()
     expect = math.ceil(S / chunk)
     assert len(calls) == expect, (calls, expect)
     assert eng.stats["prefill_calls"] == expect
-    # every admission step is batched over all slots
-    assert all(shape[0] == eng.slots for shape in calls)
+    # every admission step is batched over all slots, through the
+    # prefill program
+    assert all(kind == "prefill" and shape[0] == eng.slots
+               for kind, shape in calls)
     # one decode tick = exactly one more jitted call for all slots
     eng.tick()
-    assert len(calls) == expect + 1 and calls[-1] == (eng.slots, 1)
+    assert len(calls) == expect + 1
+    assert calls[-1] == ("decode", (eng.slots, 1))
     assert eng.stats["decode_calls"] == 1
 
 
