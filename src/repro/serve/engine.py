@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.kernels.paged_attention import live_columns
 from repro.models.config import ATTN, MAMBA, RWKV, SWA, ModelConfig
 from repro.models.layers import NEG_INF, swa_ring_blocks
 from repro.models.transformer import forward, init_cache, unembed
@@ -745,7 +746,11 @@ class ServingEngine:
     ``admitted`` and paged-pool ``backpressure`` events, and
     ``step_compiles``: the first dispatch of each step program and shape
     (``engine_step_prefill`` at every chunk width, ``engine_step_decode``),
-    where JIT compiles or loads from the persistent cache.  Each
+    where JIT compiles or loads from the persistent cache.  Kernel
+    engines count ``decode_pages_walked``, the table columns each decode
+    tick's paged-decode kernel walks (every slot's live columns, the
+    kernel's own ``live_columns``), against ``decode_pages_tabled``,
+    slots x table width a tick.  Each
     ``Request`` carries host-clock stamps ``submitted_at`` /
     ``admitted_at`` / ``prefilled_at`` (queue wait and prefill apart).
     The serving loop runs inside ``engine.*`` profiler spans (``SPAN_*``
@@ -855,7 +860,8 @@ class ServingEngine:
                       "shared_tokens": 0, "cow_copies": 0, "preempted": 0,
                       "exported": 0, "imported": 0, "import_rejects": 0,
                       "imported_pages": 0, "deduped_pages": 0,
-                      "resumed_tokens": 0, "step_compiles": 0}
+                      "resumed_tokens": 0, "step_compiles": 0,
+                      "decode_pages_walked": 0, "decode_pages_tabled": 0}
         self._seed = seed
         self._step_seq = 0
         self._admit_seq = 0
@@ -1721,6 +1727,11 @@ class ServingEngine:
                     pos[s, 0] = self.positions[s]
                 nxt, self.caches = self._call_step(toks, pos, True)
                 self.stats["decode_calls"] += 1
+                if self.use_kernel:
+                    self.stats["decode_pages_walked"] += int(live_columns(
+                        pos, self.page_size, self.n_cols).sum())
+                    self.stats["decode_pages_tabled"] += \
+                        self.slots * self.n_cols
             # every token first, then the releases: the readback span
             # holds only the host's wait for the step and its copies
             with TraceAnnotation(SPAN_READBACK):

@@ -456,15 +456,18 @@ def test_pal002_real_kernel_clean_and_drift_caught():
 
 
 # ---------------------------------------------------------------------------
-# PAL002 — dynamic cross-check: simulate the DMA schedule the grid
-# actually executes and compare against the advertised CostEstimate
+# PAL002 — dynamic cross-check: simulate the DMA schedule the kernel
+# executes and compare against the advertised CostEstimate
 # ---------------------------------------------------------------------------
 
 def test_paged_attention_cost_matches_simulated_dma_schedule(monkeypatch):
-    """Walk the real grid over the specs actually handed to pallas_call
-    (sequential page axis innermost), count a fetch whenever a spec's
-    index_map output changes between consecutive steps, and require the
-    summed bytes to equal paged_attention_cost's bytes_accessed."""
+    """Walk the real grid over the specs actually handed to pallas_call:
+    a BlockSpec'd operand is fetched whenever its index_map output
+    changes between consecutive grid steps; a pool left in HBM is copied
+    by hand, one page row block of the operand per walked table column,
+    and the cost states the walk's worst case, every column of every
+    slot.  The summed bytes must equal paged_attention_cost's
+    bytes_accessed."""
     import jax.numpy as jnp
 
     from repro.kernels import paged_attention as pa
@@ -506,31 +509,77 @@ def test_paged_attention_cost_matches_simulated_dma_schedule(monkeypatch):
     if not isinstance(out_specs, (list, tuple)):
         out_specs = [out_specs]
     out_isz = np.dtype(captured["out_shape"].dtype).itemsize
+    assert len(gs.in_specs) == len(arrays)
 
     # scalar-prefetch operands live in SMEM and are read once, whole
     simulated = sum(int(np.asarray(p).size) * np.dtype(p.dtype).itemsize
                     for p in prefetch)
-    plan = [(s, np.dtype(a.dtype).itemsize)
-            for s, a in zip(gs.in_specs, arrays)]
-    plan += [(s, out_isz) for s in out_specs]
-    assert len(gs.in_specs) == len(arrays)
+    n_tab_b, n_tab_cols = prefetch[0].shape          # the block table
+    blocked = [(s, np.dtype(a.dtype).itemsize)
+               for s, a in zip(gs.in_specs, arrays)
+               if s.block_shape is not None]
+    blocked += [(s, out_isz) for s in out_specs]
+    pools = [a for s, a in zip(gs.in_specs, arrays)
+             if s.block_shape is None]
+    assert len(pools) == 2                           # K and V, in HBM
 
-    g0, g1 = gs.grid                     # (parallel, sequential-pages)
-    for spec, isz in plan:
+    (g0,) = gs.grid                                  # one step per slot
+    for spec, isz in blocked:
         fetches, prev = 0, None
-        for bh in range(g0):
-            for ic in range(g1):
-                idx = tuple(int(x) for x in spec.index_map(
-                    bh, ic, *prefetch))
-                if idx != prev:
-                    fetches += 1
-                    prev = idx
-        blk = int(np.prod(spec.block_shape))
-        simulated += fetches * blk * isz
+        for b in range(g0):
+            idx = tuple(int(x) for x in spec.index_map(b, *prefetch))
+            if idx != prev:
+                fetches += 1
+                prev = idx
+        simulated += fetches * int(np.prod(spec.block_shape)) * isz
+    for pool in pools:                               # (N, page*Hkv, D)
+        row_block = int(np.prod(pool.shape[1:])) * pool.dtype.itemsize
+        simulated += n_tab_b * n_tab_cols * row_block
 
     assert simulated == cost.bytes_accessed, (
-        "advertised DMA bytes drifted from the BlockSpec plan: "
+        "advertised DMA bytes drifted from the kernel's plan: "
         f"simulated {simulated} vs CostEstimate {cost.bytes_accessed}")
+
+
+def test_engine_decode_pages_walked_counts_live_columns():
+    """``stats["decode_pages_walked"]`` is the sum over decode ticks of
+    each slot's live table columns, ceil((position + 1) / page) for an
+    active slot and 0 for an idle one, recounted here from the decode
+    steps' own position operands; ``decode_pages_tabled`` is slots x
+    table width per tick."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_smoke_config
+    from repro.models.transformer import init_params
+    from repro.serve.engine import Request, ServingEngine
+
+    cfg = dataclasses.replace(get_smoke_config("gpt3-24l"), vocab_size=64,
+                              d_model=64, d_ff=128, n_heads=4,
+                              n_kv_heads=2, head_dim=16)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    page, slots = 8, 3
+    eng = ServingEngine(params, cfg, slots=slots, cache_len=48, chunk=8,
+                        paged=True, page_size=page, use_kernel=True)
+    seen = []
+    real_step = eng._call_step
+
+    def spy(toks, pos, decode):
+        if decode:
+            seen.append(np.asarray(pos)[:, 0].copy())
+        return real_step(toks, pos, decode)
+
+    eng._call_step = spy
+    for i, n in enumerate([3, 9, 16, 5]):
+        eng.submit(Request(i, list(range(1, n + 1)), max_new=6))
+    eng.run()
+    assert seen and eng.stats["decode_calls"] == len(seen)
+    walked = sum(-(-(int(p) + 1) // page) for tick in seen for p in tick
+                 if p >= 0)
+    assert eng.stats["decode_pages_walked"] == walked
+    assert eng.stats["decode_pages_tabled"] == len(seen) * slots * eng.n_cols
+    assert 0 < walked < eng.stats["decode_pages_tabled"]
 
 
 # ---------------------------------------------------------------------------

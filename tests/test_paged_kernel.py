@@ -22,6 +22,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.kernels import ops
+from repro.kernels import paged_attention as pa
 from repro.models.layers import attention, swa_ring_blocks
 from repro.models.transformer import init_params
 from repro.serve.engine import Request, ServingEngine, generate
@@ -86,6 +87,109 @@ def test_paged_kernel_vs_scan(B, Hq, Hkv, D, page, n_cols, used, window,
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32),
                                atol=_tol(dtype), rtol=_tol(dtype))
+
+
+def _walk_case(q_positions, n_cols, dtype, *, B_heads=(4, 2), D=32,
+               page=8, seed=0):
+    """One slot per entry of ``q_positions``, each written like the
+    engine's pool: positions 0..q_pos in its first ``ceil((q_pos+1) /
+    page)`` columns, scattered over the pool; the rest of the row -1
+    (idle slots, q_pos -1, have none).  Blocks past the slots' pages
+    stay spare."""
+    Hq, Hkv = B_heads
+    B = len(q_positions)
+    N = B * n_cols + 4
+    rng = np.random.RandomState(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    k = jax.random.normal(ks[0], (N, page, Hkv, D), dtype)
+    v = jax.random.normal(ks[1], (N, page, Hkv, D), dtype)
+    q = jax.random.normal(ks[2], (B, 1, Hq, D), dtype)
+    pos = np.full((N, page), -1, np.int32)
+    table = np.full((B, n_cols), -1, np.int32)
+    perm = iter(rng.permutation(N))
+    for b, qp in enumerate(q_positions):
+        for c in range(-(-(qp + 1) // page)):
+            table[b, c] = blk = next(perm)
+            hi = min(page, qp + 1 - c * page)
+            pos[blk, :hi] = np.arange(c * page, c * page + hi)
+    q_pos = np.asarray(q_positions, np.int32).reshape(B, 1)
+    return q, k, v, pos, table, q_pos
+
+
+def _block_pages(page=8, Hkv=2, D=32, dtype=jnp.float32):
+    return pa._block_pages(page, Hkv, D, D, 0, jnp.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("edge", ["mid_block", "block_boundary",
+                                  "p_not_dividing_n_cols", "single_page",
+                                  "all_idle"])
+def test_paged_kernel_walk_edges(edge, dtype):
+    """The live walk in blocks of P pages against the scan path: a
+    slot's live columns ending inside a block, on a block boundary, a
+    table width P does not divide (walked whole), one live page, and no
+    live slot at all (exact zeros)."""
+    page = 8
+    P = _block_pages(dtype=dtype)
+    n_cols = 2 * P + 3
+    last = n_cols * page - 1                 # the table's last position
+    q_positions = {
+        "mid_block": [(P + 3) * page - 5, P * page + 2],
+        "block_boundary": [2 * P * page - 1, P * page - 1],
+        "p_not_dividing_n_cols": [last, last - page],
+        "single_page": [0, page - 1],
+        "all_idle": [-1, -1],
+    }[edge]
+    q, k, v, pos, table, q_pos = _walk_case(q_positions, n_cols, dtype,
+                                            page=page)
+    assert list(pa.live_columns(q_pos[:, 0], page, n_cols)) == [
+        max(0, -(-(p + 1) // page)) for p in q_positions]
+    out = ops.paged_attention(q, k, v, jnp.asarray(pos),
+                              jnp.asarray(table), jnp.asarray(q_pos))
+    if edge == "all_idle":
+        assert not np.asarray(out).any()
+        return
+    exp = attention(q, k, v, jnp.asarray(q_pos), jnp.asarray(pos),
+                    table=jnp.asarray(table), kv_chunk=page)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32),
+                               atol=_tol(dtype), rtol=_tol(dtype))
+
+
+def test_paged_kernel_never_reads_past_live_columns():
+    """Columns past a slot's live count point at pool blocks of NaN
+    whose positions look attendable: the kernel never copies them, so
+    the output is finite and equals the scan path with those columns
+    set to -1."""
+    page, n_cols = 8, 6
+    q, k, v, pos, table, q_pos = _walk_case([19, 8], n_cols, jnp.float32,
+                                            page=page, seed=4)
+    live = pa.live_columns(q_pos[:, 0], page, n_cols)          # 3, 2
+    N = k.shape[0]
+    bad = [N - 1, N - 2, N - 3]              # spare blocks, never written
+    k = k.at[jnp.asarray(bad)].set(jnp.nan)
+    v = v.at[jnp.asarray(bad)].set(jnp.nan)
+    pos[bad] = np.arange(page)               # positions <= every q_pos
+    stale = table.copy()
+    stale[0, live[0]:live[0] + 2] = bad[:2]
+    stale[1, live[1]] = bad[2]
+    out = ops.paged_attention(q, k, v, jnp.asarray(pos), jnp.asarray(stale),
+                              jnp.asarray(q_pos))
+    assert np.isfinite(np.asarray(out)).all()
+    exp = attention(q, k, v, jnp.asarray(q_pos), jnp.asarray(pos),
+                    table=jnp.asarray(table), kv_chunk=page)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_paged_kernel_rejects_odd_bf16_kv_heads():
+    """bf16 rows pair up in 32-bit words, so several kv heads in a bf16
+    pool must come in pairs: three are refused, not misread."""
+    q, k, v, pos, table, q_pos = _walk_case([5], 2, jnp.bfloat16,
+                                            B_heads=(6, 3))
+    with pytest.raises(ValueError, match="kv heads"):
+        ops.paged_attention(q, k, v, jnp.asarray(pos), jnp.asarray(table),
+                            jnp.asarray(q_pos))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -42,16 +42,17 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(one_chip, Hq, Hkv, D, De=0):
+def _compile(one_chip, Hq, Hkv, D, De=0, *, b=B, n_blocks=N_BLOCKS,
+             n_cols=N_COLS):
     def sds(shape, dtype=BF16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    args = [sds((B, 1, Hq, D)), sds((N_BLOCKS, PAGE, Hkv, D)),
-            sds((N_BLOCKS, PAGE, Hkv, D)),
-            sds((N_BLOCKS, PAGE), jnp.int32), sds((B, N_COLS), jnp.int32),
-            sds((B, 1), jnp.int32)]
+    args = [sds((b, 1, Hq, D)), sds((n_blocks, PAGE, Hkv, D)),
+            sds((n_blocks, PAGE, Hkv, D)),
+            sds((n_blocks, PAGE), jnp.int32), sds((b, n_cols), jnp.int32),
+            sds((b, 1), jnp.int32)]
     if De:
-        args += [sds((B, 1, Hq, De)), sds((N_BLOCKS, PAGE, Hkv, De))]
+        args += [sds((b, 1, Hq, De)), sds((n_blocks, PAGE, Hkv, De))]
 
     def fwd(q, k, v, pos, table, q_pos, *extra):
         kw = dict(q_extra=extra[0], k_extra=extra[1]) if extra else {}
@@ -59,7 +60,7 @@ def _compile(one_chip, Hq, Hkv, D, De=0):
                                    interpret=False, **kw)
 
     compiled = jax.jit(fwd).lower(*args).compile()
-    pool_bytes = N_BLOCKS * PAGE * Hkv * D * 2
+    pool_bytes = n_blocks * PAGE * Hkv * D * 2
     return compiled, pool_bytes
 
 
@@ -76,5 +77,21 @@ def test_paged_decode_kernel_compiles_deepseek_v3_mla(one_chip):
     latent pool (K = V) plus the 64-wide rope pool as a second score
     contraction."""
     compiled, pool_bytes = _compile(one_chip, Hq=128, Hkv=1, D=512, De=64)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize("Hq,b,n_blocks,n_cols", [
+    (32, 32, 2048, 288),    # qwen3-8b-reason: 32 slots x 4608 positions
+    (40, 8, 1152, 144),     # qwen1.5-32b-chat: groups of 5, 8 x 2304
+], ids=["qwen3-8b-reason", "qwen1.5-32b-chat"])
+def test_paged_decode_kernel_compiles_bench_geometry(one_chip, Hq, b,
+                                                     n_blocks, n_cols):
+    """The benchmark cells' decode geometries: 8 kv heads of 128, the
+    cells' slot counts, table widths and pool sizes.  A view of a pool
+    that is not a bitcast of its layout would show here as a relayout
+    copy of a whole 64 MiB pool in temp."""
+    compiled, pool_bytes = _compile(one_chip, Hq=Hq, Hkv=8, D=128, b=b,
+                                    n_blocks=n_blocks, n_cols=n_cols)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
