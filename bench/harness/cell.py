@@ -50,18 +50,17 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
     line's object.  ``devs``: the devices the run holds."""
     import jax
     counter = serve.CompileCounter()
-    model_cfg = spec.model_config(cfg)
-    m = spec.widths(cfg)
+    fam = spec.family(cfg)
+    model_cfg = fam.model_config(cfg)
+    m = fam.widths(cfg)
     wseed = weight_seed(seed)
     argv = spec.launcher_argv(cfg, mix, wseed)
 
     t = time.perf_counter()
-    engine = serve.build_engine(argv, model_cfg, m, wseed)
-    log(f"engine: {cfg['name']} {m['n_layers']}L d={m['d_model']} heads="
-        f"{m['n_heads']}/{m['n_kv_heads']}x{m['head_dim']} d_ff={m['d_ff']} "
-        f"vocab={m['vocab_size']}; slots={engine.slots} cache_len="
-        f"{engine.cache_len} chunk={engine.chunk} pool={engine.num_blocks}x"
-        f"{engine.page_size} kernel={engine.use_kernel} "
+    engine = serve.build_engine(argv, model_cfg, fam, m, wseed)
+    log(f"engine: {cfg['name']} {fam.describe(m)}; slots={engine.slots} "
+        f"cache_len={engine.cache_len} chunk={engine.chunk} pool="
+        f"{engine.num_blocks}x{engine.page_size} kernel={engine.use_kernel} "
         f"(built in {time.perf_counter() - t:.1f}s)")
     t = time.perf_counter()
     widths = serve.warm_up(engine, m["vocab_size"], seed)
@@ -105,7 +104,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
     log(f"engine stats: {stats}")
 
     page, chunk = engine.page_size, engine.chunk
-    work = M.live_work(win, reqs, m, page, chunk)
+    work = M.live_work(win, reqs, fam, m, page, chunk)
     summary = None
     if trace:
         path = TR.latest_xplane(trace_dir)
@@ -119,7 +118,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
     jax.clear_caches()
     live = sum(a.nbytes for a in jax.live_arrays())
     t = time.perf_counter()
-    cmp = check.gaps(sampled, m, wseed, control=control)
+    cmp = check.gaps(sampled, fam, m, wseed, control=control)
     limit = cfg["check"]["served_logit_gap"]
     log(f"check: {cmp['requests']} requests, {cmp['positions']} served "
         f"tokens compared with the float32 reference in "
@@ -136,8 +135,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
                                       "unit": e["unit"]}
     else:
         pk = device.peaks(devs[0].device_kind)
-        ctx = M.Context(m, page, chunk, win, reqs, work, pk,
-                        summary)
+        ctx = M.Context(m, page, chunk, win, reqs, work, pk, summary, fam)
         for p in bench["per_layer"]:
             if cell_name not in p.get("workloads", [cell_name]):
                 continue

@@ -2,13 +2,13 @@
 
 Once the window has closed and the system's state is freed, a sample of
 the requests it served, drawn from the seed and always holding the one
-with the most served tokens, is run through the float32 reference
-(``bench/reference``), teacher-forced on the prompt and the served
-tokens.  At every served position the gap by which the served token's
-reference logit lies below the reference's best is read; the number
-compared is the widest gap.  Greedy decoding in bfloat16 may pick a
-near-tie; it may not pick a token the reference ranks far below its
-best.
+with the most served tokens, is run through the float32 reference of
+the configuration's family (``bench/reference/<reference>.py``),
+teacher-forced on the prompt and the served tokens.  At every served
+position the gap by which the served token's reference logit lies below
+the reference's best is read; the number compared is the widest gap.
+Greedy decoding in bfloat16 may pick a near-tie; it may not pick a token
+the reference ranks far below its best.
 
 The control (``quant="fp8"``) puts the reference, computed with fp8
 projections, in the system's place: at each of the same positions the
@@ -36,15 +36,15 @@ def sample(reqs, n: int, seed: int) -> list:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def gaps(reqs, m: dict, weight_seed: int, control: bool = False) -> dict:
+def gaps(reqs, ref, m: dict, weight_seed: int,
+         control: bool = False) -> dict:
     """Widest gap of the compared tokens over the sampled requests, with
-    the count of positions compared.  The compared tokens are the served
-    ones, or with ``control`` the fp8 reference's first choices; the
-    served tokens' own gap is kept as ``program_logit_gap`` either way."""
+    the count of positions compared, by the family module ``ref``.  The
+    compared tokens are the served ones, or with ``control`` the fp8
+    reference's first choices; the served tokens' own gap is kept as
+    ``program_logit_gap`` either way."""
     import jax
     import jax.numpy as jnp
-
-    from bench.reference import qwen_dense as ref
 
     w = ref.draw_weights(weight_seed, m)
     worst, ctl, n_pos, bad_ids = 0.0, 0.0, 0, 0

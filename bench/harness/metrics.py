@@ -1,7 +1,7 @@
-"""Host-clock end-to-end metrics, live work counts, and the loader of the
-per-layer metric readers (``bench/metrics/<name>.py``, each a
-``read(ctx)`` that returns a number, or None where it finds nothing to
-read)."""
+"""Host-clock end-to-end metrics, live work counts (by the configuration's
+family module), and the loader of the per-layer metric readers
+(``bench/metrics/<name>.py``, each a ``read(ctx)`` that returns a number,
+or None where it finds nothing to read)."""
 from __future__ import annotations
 
 import importlib.util
@@ -9,7 +9,6 @@ import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
-from bench.harness import work as W
 from bench.harness.spec import BENCH
 
 INF = math.inf
@@ -84,17 +83,18 @@ def prefill_spans(win, by_idx, chunk: int):
     return out
 
 
-def live_work(win, reqs, m: dict, page: int, chunk: int) -> dict:
-    """FLOPs and bytes the algorithm needs over the window's ticks."""
+def live_work(win, reqs, fam, m: dict, page: int, chunk: int) -> dict:
+    """FLOPs and bytes the algorithm needs over the window's ticks, as
+    the family module ``fam`` counts them."""
     by_idx = {r.idx: r for r in reqs}
-    decode = sum(W.decode_step_flops(m, t.decode_pos) for t in win.ticks)
+    decode = sum(fam.decode_step_flops(m, t.decode_pos) for t in win.ticks)
     kf = kb = 0
     for t in win.ticks:
-        f, b = W.decode_attention_need(m, t.decode_pos, page)
+        f, b = fam.decode_attention_need(m, t.decode_pos, page)
         kf += f
         kb += b
     spans = prefill_spans(win, by_idx, chunk)
-    prefill = sum(W.prefill_flops(m, a, b, chunk) for a, b in spans)
+    prefill = sum(fam.prefill_flops(m, a, b, chunk) for a, b in spans)
     return {"decode_flops": decode, "kernel_flops": kf, "kernel_bytes": kb,
             "prefill_flops": prefill, "prefill_tokens":
             sum(b - a for a, b in spans),
@@ -114,6 +114,7 @@ class Context:
     work: dict
     peaks: Optional[dict]
     trace: object          # trace.Summary, or None without a trace
+    family: object = None  # the configuration's bench/reference module
 
     def program_s(self, kind: str) -> Optional[float]:
         """Device seconds of the engine step programs: ``decode`` (those

@@ -63,44 +63,17 @@ class CompileCounter:
         return [n for (t, n, _) in self.events if t0 <= t <= t1]
 
 
-def build_engine(argv: list, model_cfg, m: dict, weight_seed: int):
+def build_engine(argv: list, model_cfg, fam, m: dict, weight_seed: int):
     """The engine exactly as the serving launcher builds it (one replica,
-    no router), weights drawn on the device from the seed; where the
-    model has QKV biases, the reference's seeded biases replace the
-    initialiser's zeros."""
+    no router), weights drawn on the device from the seed, then set by
+    the family's ``prepare_params``."""
     import jax
     from repro.launch import serve as launcher
-    from bench.reference import qwen_dense as ref
     args = launcher.build_parser().parse_args(argv)
     engine = launcher.engine_builder(args, model_cfg)()
-    if m["attn_bias"]:
-        engine.params = with_biases(engine.params,
-                                    ref.draw_biases(weight_seed, m))
+    engine.params = fam.prepare_params(engine.params, m, weight_seed)
     jax.block_until_ready(engine.params)
     return engine
-
-
-def with_biases(params, biases: dict):
-    """``params`` with every leaf named after a key of ``biases`` replaced
-    by that array, which must have the leaf's shape and type."""
-    import jax
-    hit = []
-
-    def put(path, leaf):
-        name = getattr(path[-1], "key", None)
-        if name not in biases:
-            return leaf
-        new = biases[name]
-        if new.shape != leaf.shape or new.dtype != leaf.dtype:
-            raise ValueError(f"bias {name}: {new.shape} {new.dtype} for "
-                             f"{leaf.shape} {leaf.dtype}")
-        hit.append(name)
-        return new
-
-    out = jax.tree_util.tree_map_with_path(put, params)
-    if sorted(hit) != sorted(biases):
-        raise ValueError(f"biases set {sorted(hit)}, drawn {sorted(biases)}")
-    return out
 
 
 def warm_up(engine, vocab: int, seed: int) -> int:

@@ -1,29 +1,20 @@
 """Reads the benchmark's data by name: ``BENCHMARK.json`` at the root of
 the checkout, ``bench/configs/<config>.json`` and
-``bench/traffic/<traffic>.json``."""
+``bench/traffic/<traffic>.json``; and the configuration's family module,
+``bench/reference/<reference>.py``."""
 from __future__ import annotations
 
-import dataclasses
+import importlib
 import json
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 CHECKOUT = BENCH.parent
 
-# config-file key -> (ModelConfig field, reference/work width key)
-WIDTHS = {
-    "hidden_size": ("d_model", "d_model"),
-    "num_hidden_layers": ("n_layers", "n_layers"),
-    "num_attention_heads": ("n_heads", "n_heads"),
-    "num_key_value_heads": ("n_kv_heads", "n_kv_heads"),
-    "head_dim": ("head_dim", "head_dim"),
-    "intermediate_size": ("d_ff", "d_ff"),
-    "vocab_size": ("vocab_size", "vocab_size"),
-    "rope_theta": ("rope_theta", "rope_theta"),
-    "rms_norm_eps": ("norm_eps", "norm_eps"),
-    "qk_norm": ("qk_norm", "qk_norm"),
-    "attention_bias": ("attn_bias", "attn_bias"),
-}
+# what the harness calls of a family module, ``bench/reference/<name>.py``
+FAMILY = ("model_config", "widths", "describe", "prepare_params",
+          "draw_weights", "logits_at", "gap_of", "first_choice", "_pad",
+          "decode_step_flops", "prefill_flops", "decode_attention_need")
 
 
 def _json(path: Path) -> dict:
@@ -50,22 +41,21 @@ def traffic(name: str) -> dict:
     return _json(BENCH / "traffic" / f"{name}.json")
 
 
-def widths(cfg: dict) -> dict:
-    """The dict of widths the reference and the work counters read."""
-    m = {w: cfg[k] for k, (_, w) in WIDTHS.items()}
-    if cfg.get("tie_word_embeddings"):
-        raise ValueError("tied embeddings are not handled by the reference")
-    return m
-
-
-def model_config(cfg: dict):
-    """The system's ModelConfig for this configuration file: the
-    architecture's own config with every width set from the file."""
-    from repro.configs import get_config
-    base = get_config(cfg["arch"])
-    over = {f: cfg[k] for k, (f, _) in WIDTHS.items()}
-    return dataclasses.replace(base, name=cfg["name"],
-                               tie_embeddings=False, **over)
+def family(cfg: dict):
+    """The module of the configuration's architecture family, named by
+    its ``"reference"`` key: ``bench.reference.<reference>``, which
+    exports every function of ``FAMILY``."""
+    name = cfg.get("reference")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise ValueError(f"configuration {cfg.get('name')!r}: key "
+                         f"'reference' must name a module of bench/reference"
+                         f" (got {name!r})")
+    mod = importlib.import_module(f"bench.reference.{name}")
+    missing = [f for f in FAMILY if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"family module bench.reference.{name} lacks "
+                         f"{', '.join(missing)}")
+    return mod
 
 
 def launcher_argv(cfg: dict, mix: dict, weight_seed: int) -> list:

@@ -1,10 +1,10 @@
 """The paged-decode kernel's share of its roofline: least time the
 attention needs over the summed device time of the kernel's operations
-(%).  Needed: each live slot's K and V pages up to its position, its
-query and its output, per layer; FLOPs of scores and weighted values
-over its own keys.  The least time is the larger of bytes / HBM
-bandwidth and FLOPs / bf16 peak (the bytes bound it at these shapes).
-Moves ``out_tok_s``."""
+(%).  The needed FLOPs and bytes of each decode step, over the live
+slots and every layer, are those the configuration's family module
+defines (``decode_attention_need`` in ``bench/reference/<family>.py``).
+The least time is the larger of bytes / HBM bandwidth and FLOPs / bf16
+peak.  Moves ``out_tok_s``."""
 
 
 def read(ctx):

@@ -1,7 +1,7 @@
 """Whole decode step's share of the bf16 peak: model FLOPs of the live
-slots' tokens decoded in the window (projections, attention over each
-slot's own length, unembed) over the decode programs' device time times
-the peak (%).  Moves ``out_tok_s``."""
+slots' tokens decoded in the window, as the configuration's family
+module counts them (``decode_step_flops``), over the decode programs'
+device time times the peak (%).  Moves ``out_tok_s``."""
 
 
 def read(ctx):

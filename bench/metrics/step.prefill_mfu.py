@@ -1,7 +1,7 @@
-"""Model FLOPs of the live prompt tokens prefilled in the window
-(projections, causal attention, one unembed per chunk) over the device
-time of the prefill programs times the chip's bf16 peak (%).  Masked
-rows of the (slots, chunk) program are not counted."""
+"""Model FLOPs of the live prompt tokens prefilled in the window, as the
+configuration's family module counts them (``prefill_flops``), over the
+device time of the prefill programs times the chip's bf16 peak (%).
+Masked rows of the (slots, chunk) program are not counted."""
 
 
 def read(ctx):

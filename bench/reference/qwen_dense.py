@@ -1,6 +1,18 @@
-"""Plain float32 reference of the dense Qwen decoder (Qwen3 with per-head
-qk-norm, Qwen1.5 with QKV bias), written from the published model
-description and independent of the system under test.
+"""The dense Qwen family (Qwen3 with per-head qk-norm, Qwen1.5 with QKV
+bias): everything the benchmark knows of it.  A configuration file names
+this module under ``"reference": "qwen_dense"``, and the harness reaches
+the family only through the functions below (``bench/harness/spec.py``
+``family``).
+
+* ``model_config(cfg)`` and ``widths(cfg)`` read the configuration file:
+  the system's ModelConfig, and the dict ``m`` of widths that everything
+  else here takes.  ``describe(m)`` is the widths part of the engine log
+  line.
+* ``prepare_params(params, m, seed)`` sets in the system's weights what
+  the benchmark draws and the initialiser does not: the QKV biases.
+
+The plain float32 reference, written from the published model
+description and independent of the system under test:
 
 * ``draw_weights(seed, m)`` draws the seeded random weights in bfloat16
   by the same recipe as the serving system's weight initialiser (a
@@ -21,12 +33,21 @@ description and independent of the system under test.
   scale, as an fp8 serving path would compute.  It must fail the
   comparison.
 
+The work counts: ``decode_step_flops``, ``prefill_flops`` and
+``decode_attention_need`` give the operations and bytes the algorithm
+needs, from the widths and the live lengths the run recorded.  Work that
+the program walks but the algorithm does not need (masked batch rows,
+unallocated block-table columns, pages past a row's length) is never
+counted.  They hold for grouped-query attention with K and V cached per
+kv head; a family that caches otherwise (a shared latent) counts its own.
+
 ``m`` is a plain dict of widths: d_model, n_layers, n_heads,
 n_kv_heads, head_dim, d_ff, vocab_size, rope_theta, norm_eps,
 qk_norm, attn_bias.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import partial
 
@@ -43,6 +64,80 @@ SEQ_BUCKET = 512         # sequences are padded to a multiple of this
 # residual at random weights, the logits turn peaked and no near-tie is
 # left for the fp8 control to flip.
 BIAS_STD = 0.1
+BF16_BYTES = 2
+
+# config-file key -> (ModelConfig field, key of ``m``)
+WIDTHS = {
+    "hidden_size": ("d_model", "d_model"),
+    "num_hidden_layers": ("n_layers", "n_layers"),
+    "num_attention_heads": ("n_heads", "n_heads"),
+    "num_key_value_heads": ("n_kv_heads", "n_kv_heads"),
+    "head_dim": ("head_dim", "head_dim"),
+    "intermediate_size": ("d_ff", "d_ff"),
+    "vocab_size": ("vocab_size", "vocab_size"),
+    "rope_theta": ("rope_theta", "rope_theta"),
+    "rms_norm_eps": ("norm_eps", "norm_eps"),
+    "qk_norm": ("qk_norm", "qk_norm"),
+    "attention_bias": ("attn_bias", "attn_bias"),
+}
+
+
+# ---------------------------------------------------------------------------
+# The configuration file
+# ---------------------------------------------------------------------------
+
+def widths(cfg: dict) -> dict:
+    """The dict of widths the reference and the work counters read."""
+    m = {w: cfg[k] for k, (_, w) in WIDTHS.items()}
+    if cfg.get("tie_word_embeddings"):
+        raise ValueError("tied embeddings are not handled by the reference")
+    return m
+
+
+def model_config(cfg: dict):
+    """The system's ModelConfig for this configuration file: the
+    architecture's own config with every width set from the file."""
+    from repro.configs import get_config
+    base = get_config(cfg["arch"])
+    over = {f: cfg[k] for k, (f, _) in WIDTHS.items()}
+    return dataclasses.replace(base, name=cfg["name"],
+                               tie_embeddings=False, **over)
+
+
+def describe(m: dict) -> str:
+    return (f"{m['n_layers']}L d={m['d_model']} heads={m['n_heads']}/"
+            f"{m['n_kv_heads']}x{m['head_dim']} d_ff={m['d_ff']} "
+            f"vocab={m['vocab_size']}")
+
+
+def prepare_params(params, m: dict, seed: int):
+    """The system's weights with, where the model has QKV biases, the
+    seeded biases in place of the initialiser's zeros."""
+    if not m["attn_bias"]:
+        return params
+    return with_biases(params, draw_biases(seed, m))
+
+
+def with_biases(params, biases: dict):
+    """``params`` with every leaf named after a key of ``biases`` replaced
+    by that array, which must have the leaf's shape and type."""
+    hit = []
+
+    def put(path, leaf):
+        name = getattr(path[-1], "key", None)
+        if name not in biases:
+            return leaf
+        new = biases[name]
+        if new.shape != leaf.shape or new.dtype != leaf.dtype:
+            raise ValueError(f"bias {name}: {new.shape} {new.dtype} for "
+                             f"{leaf.shape} {leaf.dtype}")
+        hit.append(name)
+        return new
+
+    out = jax.tree_util.tree_map_with_path(put, params)
+    if sorted(hit) != sorted(biases):
+        raise ValueError(f"biases set {sorted(hit)}, drawn {sorted(biases)}")
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -217,3 +312,58 @@ def gap_of(ref_logits, toks):
 @jax.jit
 def first_choice(logits):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# Work the algorithm needs
+# ---------------------------------------------------------------------------
+
+def matmul_flops_per_token(m: dict) -> int:
+    """Projection FLOPs of one token through every layer (no unembed)."""
+    d, hq, hkv, hd, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                         m["head_dim"], m["d_ff"])
+    per_layer = 2 * d * (hq + 2 * hkv) * hd + 2 * hq * hd * d + 3 * 2 * d * f
+    return per_layer * m["n_layers"]
+
+
+def attention_flops(m: dict, pos: int) -> int:
+    """Score and value FLOPs of one query at position ``pos`` (it sees
+    pos + 1 keys), over every layer."""
+    return 4 * m["n_heads"] * m["head_dim"] * (pos + 1) * m["n_layers"]
+
+
+def unembed_flops(m: dict) -> int:
+    return 2 * m["d_model"] * m["vocab_size"]
+
+
+def decode_step_flops(m: dict, positions) -> int:
+    """One decode step of the live slots at query ``positions``: each
+    slot's token through every layer, its attention, and its unembed."""
+    return sum(matmul_flops_per_token(m) + attention_flops(m, p)
+               + unembed_flops(m) for p in positions)
+
+
+def prefill_flops(m: dict, start: int, stop: int, chunk: int) -> int:
+    """Prompt positions [start, stop) of one request, prefilled in chunks
+    of ``chunk``: every live token's projections and causal attention,
+    and one unembed per chunk (only a chunk's last position is read)."""
+    n = stop - start
+    attn = sum(attention_flops(m, p) for p in range(start, stop))
+    chunks = -(-n // chunk)
+    return n * matmul_flops_per_token(m) + attn + chunks * unembed_flops(m)
+
+
+def decode_attention_need(m: dict, positions, page: int) -> tuple:
+    """(FLOPs, bytes) the paged decode attention needs in one step, over
+    every layer: each live slot's K and V pages up to its position, its
+    query and its output; scores and weighted values over pos + 1 keys."""
+    hq, hkv, hd, L = m["n_heads"], m["n_kv_heads"], m["head_dim"], m["n_layers"]
+    flops = 0
+    nbytes = 0
+    for p in positions:
+        pages = -(-(p + 1) // page)
+        kv = 2 * pages * page * hkv * hd * BF16_BYTES
+        qo = 2 * hq * hd * BF16_BYTES
+        nbytes += (kv + qo) * L
+        flops += 4 * hq * hd * (p + 1) * L
+    return flops, nbytes

@@ -43,10 +43,11 @@ def main(argv=None) -> None:
     jax.config.update("jax_compilation_cache_dir", str(R.CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     device.require_tpu(cell["chips"])
-    m = spec.widths(cfg)
+    fam = spec.family(cfg)
+    m = fam.widths(cfg)
     wseed = weight_seed(a.seed)
     engine = serve.build_engine(spec.launcher_argv(cfg, mix, wseed),
-                                spec.model_config(cfg), m, wseed)
+                                fam.model_config(cfg), fam, m, wseed)
     serve.warm_up(engine, m["vocab_size"], a.seed)
     for rate in [float(x) for x in a.rates.split(",")]:
         rmix = dict(mix, rate_per_s=rate)

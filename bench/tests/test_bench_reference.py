@@ -6,8 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.harness import serve, spec
-from bench.reference import qwen_dense as ref
+from bench.harness import spec
 from bench.tests import tiny
 
 
@@ -15,18 +14,21 @@ from bench.tests import tiny
                          ids=["qwen3-qk-norm", "qwen1.5-qkv-bias"])
 def test_reference_matches_the_model(cfg):
     from repro.models.transformer import forward, init_params
-    mc, m = spec.model_config(cfg), spec.widths(cfg)
+    ref = spec.family(cfg)
+    mc, m = ref.model_config(cfg), ref.widths(cfg)
     seed = 2 ** 31 + 11
     params = jax.jit(init_params, static_argnums=1)(
         jax.random.PRNGKey(seed), mc)
     w = ref.draw_weights(seed, m)
+    # the benchmark's seeded biases, set as the harness sets them
+    params = ref.prepare_params(params, m, seed)
+    st = params["stack"][0]
     if m["attn_bias"]:
-        # the benchmark's seeded biases, set as the harness sets them
         biases = ref.draw_biases(seed, m)
         assert all(float(jnp.abs(b).mean()) > ref.BIAS_STD / 2
                    for b in biases.values())
-        params = serve.with_biases(params, biases)
-    st = params["stack"][0]
+        for name, b in biases.items():
+            assert bool(jnp.array_equal(st["mixer"][name], b))
     for ours, theirs in [(w["embed"], params["embed"]),
                          (w["lm_head"], params["lm_head"]),
                          (w["layers"]["wq"], st["mixer"]["wq"]),
@@ -45,7 +47,8 @@ def test_reference_matches_the_model(cfg):
 
 
 def test_fp8_control_departs_from_the_reference():
-    m = spec.widths(tiny.QWEN3)
+    ref = spec.family(tiny.QWEN3)
+    m = ref.widths(tiny.QWEN3)
     w = ref.draw_weights(5, m)
     toks = np.random.default_rng(1).integers(0, m["vocab_size"], 24)
     a = ref.logits_at(w, m, toks, np.arange(24))[:24]

@@ -2,7 +2,7 @@
 as the cells, at widths the CPU runs in seconds."""
 
 QWEN3 = {
-    "name": "qwen3-tiny", "arch": "qwen3-8b",
+    "name": "qwen3-tiny", "arch": "qwen3-8b", "reference": "qwen_dense",
     "hidden_size": 64, "num_hidden_layers": 2, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
     "vocab_size": 512, "rope_theta": 1000000.0, "rms_norm_eps": 1e-06,
